@@ -1,8 +1,10 @@
-"""Kernel micro-benchmarks (interpret-mode correctness + host timing).
+"""Kernel micro-benchmarks (correctness vs the oracles + host timing).
 
-Wall times here are CPU interpret-mode numbers — NOT TPU performance;
-the derived column reports the correctness deltas vs the oracles and
-the arithmetic-intensity characteristics that matter on the target.
+On a TPU the kernels run compiled; on any other backend they run in
+the Pallas interpreter, and the wall times are interpreter numbers —
+NOT TPU performance.  The derived column reports the correctness
+deltas vs the oracles and the arithmetic-intensity characteristics
+that matter on the target.
 """
 from __future__ import annotations
 
@@ -14,6 +16,9 @@ import numpy as np
 
 from benchmarks.util import emit
 
+#: the Pallas interpreter runs wherever the TPU compiler cannot
+INTERPRET = jax.default_backend() != "tpu"
+
 
 def bench_flash_attention():
     from repro.kernels.flash_attention import flash_attention, mha_reference
@@ -22,9 +27,10 @@ def bench_flash_attention():
     q = jnp.asarray(rng.standard_normal((b, hq, s, d)), jnp.bfloat16)
     k = jnp.asarray(rng.standard_normal((b, hkv, s, d)), jnp.bfloat16)
     v = jnp.asarray(rng.standard_normal((b, hkv, s, d)), jnp.bfloat16)
-    o = flash_attention(q, k, v, causal=True)
+    o = flash_attention(q, k, v, causal=True, interpret=INTERPRET)
     t0 = time.perf_counter()
-    o = flash_attention(q, k, v, causal=True).block_until_ready()
+    o = flash_attention(q, k, v, causal=True,
+                        interpret=INTERPRET).block_until_ready()
     us = (time.perf_counter() - t0) * 1e6
     r = mha_reference(q, k, v, causal=True)
     err = float(jnp.max(jnp.abs(o.astype(jnp.float32)
@@ -45,9 +51,9 @@ def bench_bank_timing():
             r(2), r(2), r(1000)]
     ch = pack_scalars(jnp.int32(50), r(100, (C,)), r(100, (C,)),
                       r(100, (C,)), r(2, (C,)), r(8, (C,)))
-    sel, cmd = frfcfs_select(*args, ch)
+    sel, cmd = frfcfs_select(*args, ch, interpret=INTERPRET)
     t0 = time.perf_counter()
-    sel, cmd = frfcfs_select(*args, ch)
+    sel, cmd = frfcfs_select(*args, ch, interpret=INTERPRET)
     jax.block_until_ready((sel, cmd))
     us = (time.perf_counter() - t0) * 1e6
     sr, cr = select_reference(*args, scalars_tuple(ch))
@@ -60,9 +66,9 @@ def bench_addr_decode():
     from repro.kernels.addr_decode import decode_skylake, decode_reference
     rng = np.random.default_rng(2)
     lines = jnp.asarray(rng.integers(0, 2 ** 32, 1 << 16, dtype=np.uint32))
-    d = decode_skylake(lines)
+    d = decode_skylake(lines, interpret=INTERPRET)
     t0 = time.perf_counter()
-    d = decode_skylake(lines)
+    d = decode_skylake(lines, interpret=INTERPRET)
     jax.block_until_ready(d.channel)
     us = (time.perf_counter() - t0) * 1e6
     r = decode_reference(lines)

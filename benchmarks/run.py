@@ -15,6 +15,7 @@ import sys
 import time
 
 from benchmarks.registry import BENCHMARKS, get_benchmark
+from repro.compile_cache import enable_compile_cache
 
 
 def main() -> None:
@@ -35,6 +36,7 @@ def main() -> None:
 
     names = args.only.split(",") if args.only else list(BENCHMARKS)
     specs = [get_benchmark(n) for n in names]
+    print(f"# compile cache: {enable_compile_cache()}", file=sys.stderr)
     print("name,us_per_call,derived")
     t0 = time.time()
     for spec in specs:

@@ -5,12 +5,16 @@ its batch axis — Mess pace points or stacked application traces — is
 embarrassingly parallel.  `sharded_vmap` maps that axis across every
 available accelerator with `jax.shard_map` (data-parallel, no
 cross-shard communication) and degenerates to a plain `jax.vmap` on a
-single device, so CPU CI and a TPU pod run the same call sites.
+single device, so a CPU run, one TPU chip and a multi-chip host run the
+same call sites.
 
 Because the mapped function is elementwise along the batch axis (no
 collectives, no cross-batch reductions), the sharded result is
-**bit-identical** to the single-device vmap result — asserted by
-tests/test_sharding_sweeps.py.
+**bit-identical** to the single-device vmap result — asserted on forced
+CPU devices by tests/test_sharding_sweeps.py and on four TPU chips by
+``python chip_smoke.py --chips 4``.  The same property is why the
+varying-manual-axes check is off (``check_vma=False``): no value ever
+crosses shards, so there is nothing for it to guard.
 
 Batch sizes that do not divide the device count are right-padded by
 repeating the last element; `sharded_vmap` slices the padding off the
@@ -23,11 +27,6 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec
-
-try:                                    # jax >= 0.5 exposes it top-level
-    from jax import shard_map as _shard_map       # type: ignore
-except ImportError:
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 BATCH_AXIS = "batch"
 
@@ -46,6 +45,20 @@ def _pad_batch(tree, pad: int):
 
 def _unpad_batch(tree, n: int):
     return jax.tree_util.tree_map(lambda a: a[:n], tree)
+
+
+def shard_mapped(fn, mesh: Mesh):
+    """``vmap(fn)`` with its leading axis split over ``mesh``'s batch axis.
+
+    The un-jitted program `sharded_vmap` runs on more than one device;
+    the batch length must be a multiple of the mesh size.
+    """
+    spec = PartitionSpec(BATCH_AXIS)
+    # check_vma=False: a `lax.scan` carry that starts unvarying comes
+    # out varying over `batch`, which the check rejects; fn has no
+    # collectives, so per-shard values never need the check
+    return jax.shard_map(jax.vmap(fn), mesh=mesh, in_specs=spec,
+                         out_specs=spec, check_vma=False)
 
 
 def sharded_vmap(fn, n_devices: int | None = None, donate: bool = False):
@@ -79,10 +92,7 @@ def sharded_vmap(fn, n_devices: int | None = None, donate: bool = False):
     if nd <= 1:
         return jax.jit(jax.vmap(fn), donate_argnums=dn)
 
-    mesh = Mesh(jax.devices()[:nd], (BATCH_AXIS,))
-    spec = PartitionSpec(BATCH_AXIS)
-    mapped = _shard_map(jax.vmap(fn), mesh=mesh,
-                        in_specs=spec, out_specs=spec)
+    mapped = shard_mapped(fn, Mesh(jax.devices()[:nd], (BATCH_AXIS,)))
     jitted = jax.jit(mapped, donate_argnums=dn)
 
     @functools.wraps(fn)

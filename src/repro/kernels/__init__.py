@@ -3,7 +3,8 @@
 Each kernel package ships three modules:
 
 * ``kernel.py`` — the ``pl.pallas_call`` body with explicit BlockSpec
-  VMEM tiling (TPU is the target; ``interpret=True`` validates on CPU),
+  VMEM tiling; it compiles for the TPU by default, and CPU callers pass
+  ``interpret=True`` to run the Pallas interpreter,
 * ``ops.py``    — the jit'd public wrapper (padding, GQA folding,
   shape plumbing),
 * ``ref.py``    — the pure-jnp oracle the tests sweep against.

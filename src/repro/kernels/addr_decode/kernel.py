@@ -8,8 +8,10 @@ coordinates into one uint32 per line (row 17b | col 7b | bank 4b |
 rank 1b | channel 3b), keeping the output lane-aligned and letting the
 caller unpack only the fields it needs.
 
-Tiling: 1-D stream reshaped to (blocks, 1024) — 8 sublanes x 128 lanes
-per VREG tile of int32.
+Tiling: the 1-D stream is zero-padded and reshaped to (rows, 1024)
+with rows a multiple of 8; each grid step takes an (8, 1024) block, the
+(8 sublanes x 128 lanes) tiling the TPU compiler requires of the last
+two block dimensions.
 """
 from __future__ import annotations
 
@@ -19,7 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-BLOCK = 1024
+BLOCK = 1024        # lanes per row
+ROWS = 8            # sublanes per grid step
 
 # packed-field shifts / widths
 CH_SH, CH_W = 0, 3
@@ -34,7 +37,7 @@ def _bit(x, i):
 
 
 def _decode_kernel(line_ref, out_ref):
-    line = line_ref[0].astype(jnp.uint32)
+    line = line_ref[...].astype(jnp.uint32)
     mc = _bit(line, 0) ^ _bit(line, 6) ^ _bit(line, 11) ^ _bit(line, 17)
     ch3 = ((line >> 1) ^ (line >> 7) ^ (line >> 13) ^ (line >> 19)) % 3
     ch = mc * 3 + ch3
@@ -46,25 +49,30 @@ def _decode_kernel(line_ref, out_ref):
     rank = _bit(line, 8) ^ _bit(line, 18)
     col = (line ^ (line >> 9)) % jnp.uint32(128)
     row = (line >> 9) & jnp.uint32(0x1FFFF)
-    out_ref[0, :] = (ch
-                     | (rank << RANK_SH)
-                     | (bank << BANK_SH)
-                     | (col << COL_SH)
-                     | (row << ROW_SH)).astype(jnp.uint32)
+    out_ref[...] = (ch
+                    | (rank << RANK_SH)
+                    | (bank << BANK_SH)
+                    | (col << COL_SH)
+                    | (row << ROW_SH)).astype(jnp.uint32)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def decode_packed(lines, *, interpret: bool = True):
-    """Decode (N,) uint32 cache-line indices -> (N,) packed coordinates."""
+def decode_packed(lines, *, interpret: bool = False):
+    """Decode (N,) uint32 cache-line indices -> (N,) packed coordinates.
+
+    ``interpret=True`` runs the Pallas interpreter (CPU callers).
+    """
     n = lines.shape[0]
-    n_pad = -(-n // BLOCK) * BLOCK
+    tile = ROWS * BLOCK
+    n_pad = -(-n // tile) * tile
     x = jnp.pad(lines.astype(jnp.uint32), (0, n_pad - n))
     x = x.reshape(n_pad // BLOCK, BLOCK)
+    spec = pl.BlockSpec((ROWS, BLOCK), lambda i: (i, 0))
     out = pl.pallas_call(
         _decode_kernel,
-        grid=(n_pad // BLOCK,),
-        in_specs=[pl.BlockSpec((1, BLOCK), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, BLOCK), lambda i: (i, 0)),
+        grid=(n_pad // tile,),
+        in_specs=[spec],
+        out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((n_pad // BLOCK, BLOCK), jnp.uint32),
         interpret=interpret,
     )(x)

@@ -5,7 +5,7 @@ from repro.core.addrmap import DecodedAddr
 from repro.kernels.addr_decode.kernel import decode_packed, unpack
 
 
-def decode_skylake(lines, *, interpret: bool = True) -> DecodedAddr:
+def decode_skylake(lines, *, interpret: bool = False) -> DecodedAddr:
     """(N,) uint32 cache-line indices -> DecodedAddr via the kernel."""
     ch, rank, bank, row, col = unpack(decode_packed(lines,
                                                     interpret=interpret))

@@ -28,10 +28,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = float("-inf")
 
-# jax renamed TPUCompilerParams -> CompilerParams across releases; accept both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
-
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
                   scale: float, causal: bool, sq: int, sk: int,
@@ -89,7 +85,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 def flash_attention_bhsd(q, k, v, *, causal: bool = False,
                          scale: float | None = None,
                          block_q: int = 128, block_k: int = 128,
-                         interpret: bool = True):
+                         interpret: bool = False):
     """Flash attention over flattened heads.
 
     q: (BH, Sq, D); k, v: (BH, Sk, D), all pre-padded so that
@@ -125,7 +121,7 @@ def flash_attention_bhsd(q, k, v, *, causal: bool = False,
             pltpu.VMEM((block_q, 1), jnp.float32),    # running denom
             pltpu.VMEM((block_q, d_p), jnp.float32),  # output accumulator
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qp, kp, vp)

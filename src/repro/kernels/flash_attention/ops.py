@@ -15,7 +15,7 @@ from repro.kernels.flash_attention.kernel import flash_attention_bhsd
 def flash_attention(q, k, v, *, causal: bool = False,
                     scale: float | None = None,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True):
+                    interpret: bool = False):
     """GQA flash attention.
 
     q: (B, Hq, Sq, D); k, v: (B, Hkv, Sk, D), Hq % Hkv == 0.

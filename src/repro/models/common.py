@@ -263,7 +263,8 @@ def attention(cfg: ModelConfig, q, k, v, *, causal: bool, chunk: int = 1024):
     if cfg.use_flash_kernel:
         from repro.kernels.flash_attention import flash_attention
         o = flash_attention(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-                            v.transpose(0, 2, 1, 3), causal=causal)
+                            v.transpose(0, 2, 1, 3), causal=causal,
+                            interpret=jax.default_backend() == "cpu")
         return o.transpose(0, 2, 1, 3)
     return _chunked_attention(q, k, v, causal=causal, chunk=chunk,
                               softcap=cfg.attn_logit_softcap)
