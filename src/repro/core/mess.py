@@ -28,8 +28,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.platform import StageConfig, run_point
+from repro.core.platform import StageConfig, count_launch, run_point
 from repro.core.shard import sharded_vmap
+from repro.obs import spans
 
 #: write-fraction numerators out of 64 -> read fractions 100..50%
 #: (Mess plots 100%-read lightest to 50%-read darkest).
@@ -192,6 +193,20 @@ def event_covers(cfg: StageConfig, pace: int) -> bool:
     return est <= cfg.event_budget()
 
 
+def _launch(cfg: StageConfig, paces, wr, reruns: int = 0) -> dict:
+    """One compiled launch over ``paces`` at write mix ``wr``, on the
+    host, under the engine's span and counters (`platform.count_launch`;
+    the last ``reruns`` paces re-run saturated event points)."""
+    with spans.span(f"repro.mess.{cfg.weave}"):
+        pv = jnp.asarray(paces, jnp.int32)
+        res = _sweep_fn(cfg)((pv, jnp.full_like(pv, wr)))
+        with spans.span(f"repro.mess.{cfg.weave}.fetch"):
+            out = jax.device_get(res)
+    count_launch(cfg, len(paces), reruns, out["weave_events"])
+    return out
+
+
+@spans.span("repro.mess.mix")
 def _run_mix(cfg: StageConfig, paces, wr):
     """One write-mix row, knee-routed between the weave engines.
 
@@ -214,39 +229,40 @@ def _run_mix(cfg: StageConfig, paces, wr):
                          "knee-routed engine mix; run run_frontend "
                          "with an explicit weave engine instead")
     if cfg.weave != "event":
-        pace_v = jnp.asarray(paces, jnp.int32)
-        return jax.device_get(_sweep_fn(cfg)(
-            (pace_v, jnp.full_like(pace_v, wr))))
+        return _launch(cfg, paces, wr)
 
-    _ensure_calibration()
-    cfg_dense = dataclasses.replace(cfg, weave="dense")
-    ev = [i for i, p in enumerate(paces) if event_covers(cfg, p)]
-    dn = [i for i in range(n) if i not in ev]
+    with spans.span("repro.mess.route"):
+        _ensure_calibration()
+        cfg_dense = dataclasses.replace(cfg, weave="dense")
+        ev = [i for i, p in enumerate(paces) if event_covers(cfg, p)]
+        dn = [i for i in range(n) if i not in ev]
+    reruns = 0
     parts = {}
     if ev:
-        pv = jnp.asarray([paces[i] for i in ev], jnp.int32)
-        out = jax.device_get(_sweep_fn(cfg)((pv, jnp.full_like(pv, wr))))
+        out = _launch(cfg, [paces[i] for i in ev], wr)
         sat = np.asarray(out["weave_sat"]) > 0
         if sat.any():                      # estimator missed: go exact
+            reruns = int(sat.sum())
             dn += [ev[j] for j in np.flatnonzero(sat)]
             ev = [ev[j] for j in np.flatnonzero(~sat)]
             out = {k: np.asarray(v)[~sat] for k, v in out.items()}
         parts["ev"] = (ev, out)
     if dn:
-        pv = jnp.asarray([paces[i] for i in dn], jnp.int32)
-        parts["dn"] = (dn, jax.device_get(_sweep_fn(cfg_dense)(
-            (pv, jnp.full_like(pv, wr)))))
-    first = next(iter(parts.values()))[1]
-    merged = {}
-    for k in first:
-        proto = np.asarray(first[k])
-        col = np.empty((n,) + proto.shape[1:], proto.dtype)
-        for (idx, v) in parts.values():
-            col[np.asarray(idx, int)] = np.asarray(v[k])
-        merged[k] = col
+        parts["dn"] = (dn, _launch(cfg_dense, [paces[i] for i in dn], wr,
+                                   reruns))
+    with spans.span("repro.mess.merge"):
+        first = next(iter(parts.values()))[1]
+        merged = {}
+        for k in first:
+            proto = np.asarray(first[k])
+            col = np.empty((n,) + proto.shape[1:], proto.dtype)
+            for (idx, v) in parts.values():
+                col[np.asarray(idx, int)] = np.asarray(v[k])
+            merged[k] = col
     return merged
 
 
+@spans.span("repro.mess.sweep")
 def sweep(cfg: StageConfig, paces=DEFAULT_PACES,
           write_mixes=WRITE_MIXES) -> SweepResult:
     """Run the Mess characterization of one simulation stage.

@@ -33,6 +33,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import dram, workload
 from repro.core.clocking import ClockModel, make_clock
@@ -40,6 +41,7 @@ from repro.core.dram import SchedulerPolicy
 from repro.core.noc import NocModel, make_noc
 from repro.core.timing import PlatformParams, DEFAULT_PLATFORM
 from repro.core.workload import WorkloadConfig
+from repro.obs import spans
 
 PI_KEEP = 0.95       # paper: 95% previous estimate
 PI_BLEND = 0.05      # paper: 5% new cycle-accurate average
@@ -115,6 +117,12 @@ class StageConfig:
         """Event-scan steps per window (override or clock-derived)."""
         return self.weave_events or self.clock().events_per_window_static
 
+    def scan_steps(self) -> int:
+        """Weave scan steps per window of this stage's engine."""
+        if self.weave == "event":
+            return self.event_budget()
+        return self.clock().ticks_per_window_static
+
     def noc_model(self) -> NocModel:
         return make_noc(self.noc)
 
@@ -149,13 +157,16 @@ def _window_step(cfg: StageConfig, clock: ClockModel, wcfg: WorkloadConfig,
     l_ir_cycles = jnp.maximum(jnp.round(l_ir).astype(jnp.int32), 1)
     window_ps = cpu.window_cycles * cpu.cpu_ps_per_clk
 
-    # bound phase + interface hand-off (MSHR closed-loop budget)
-    budget = workload.littles_law_budget(lat_est, window_ps)
-    cand, aux = frontend.bound(fstate, l_ir_cycles, budget,
-                               cpu.window_cycles)
-    queue, acc_demand, injected = workload.inject_queue(queue, cand,
-                                                        clock, w, wcfg)
-    fstate = frontend.update(fstate, aux, acc_demand)
+    # bound phase + interface hand-off (MSHR closed-loop budget).  The
+    # named scopes here and below only label the ops in a profile.
+    with jax.named_scope("bound"):
+        budget = workload.littles_law_budget(lat_est, window_ps)
+        cand, aux = frontend.bound(fstate, l_ir_cycles, budget,
+                                   cpu.window_cycles)
+    with jax.named_scope("inject"):
+        queue, acc_demand, injected = workload.inject_queue(
+            queue, cand, clock, w, wcfg)
+        fstate = frontend.update(fstate, aux, acc_demand)
     if cfg.telemetry:
         # interface-view series: per-channel queue depth right after
         # this window's injection (window boundaries are engine-
@@ -200,13 +211,15 @@ def _window_step(cfg: StageConfig, clock: ClockModel, wcfg: WorkloadConfig,
         def body(qba, i):
             q, b, acc, tacc, ts = qba
             t = start + i
-            q, b, s, *rest = tick_fn(q, b, t, active=t < end, tele=ts)
+            with jax.named_scope("tick"):
+                q, b, s, *rest = tick_fn(q, b, t, active=t < end, tele=ts)
             ti, ts, cmd = split_extras(rest)
             return (q, b, tree_add(acc, s), tree_add(tacc, ti), ts), cmd
 
-        (queue, banks, st, tacc, tstate), cmds = jax.lax.scan(
-            body, (queue, banks, acc0, tacc0, tstate),
-            jnp.arange(clock.ticks_per_window_static, dtype=jnp.int32))
+        with jax.named_scope("weave"):
+            (queue, banks, st, tacc, tstate), cmds = jax.lax.scan(
+                body, (queue, banks, acc0, tacc0, tstate),
+                jnp.arange(clock.ticks_per_window_static, dtype=jnp.int32))
         weave_events = end - start
         weave_sat = jnp.zeros((), bool)
     else:
@@ -225,31 +238,36 @@ def _window_step(cfg: StageConfig, clock: ClockModel, wcfg: WorkloadConfig,
 
         def ebody(qbta, i):
             q, b, t, acc, tacc, ts = qbta
-            tn = nev_fn(q, b, t, horizon)           # (C,)
+            with jax.named_scope("next_event"):
+                tn = nev_fn(q, b, t, horizon)       # (C,)
             live = tn < horizon
             tau = jnp.minimum(tn, horizon - 1)
-            q, b, s, *rest = tick_fn(q, b, tau,
-                                     active=live & (tau < end), tele=ts)
+            with jax.named_scope("tick"):
+                q, b, s, *rest = tick_fn(q, b, tau,
+                                         active=live & (tau < end), tele=ts)
             ti, ts, cmd = split_extras(rest)
             return (q, b, tau, tree_add(acc, s),
                     tree_add(tacc, ti), ts), (tn < end, cmd)
 
-        (queue, banks, t_last, st, tacc, tstate), (live, cmds) = jax.lax.scan(
-            ebody, (queue, banks, t0 * (start - 1), acc0, tacc0, tstate),
-            jnp.arange(cfg.event_budget(), dtype=jnp.int32))
-        # the binding constraint is the busiest channel's event count
-        weave_events = jnp.max(jnp.sum(live.astype(jnp.int32), axis=0))
-        # budget exhausted with events still pending anywhere before
-        # the static horizon: spilled events replay next window
-        # (graceful) and the window is flagged — never silent.  The
-        # check runs against `horizon`, not `end`: a pending *tail*
-        # event (an arrival in [end, horizon)) carries a drain-
-        # hysteresis update the dense scan's inactive ticks would have
-        # applied, so skipping it must flag too, or the sat=0 =>
-        # bit-identical contract (relied on by `mess._run_mix` and
-        # `traces.replay._replay_exact`) would leak a silent
-        # divergence into the next window.
-        weave_sat = jnp.any(nev_fn(queue, banks, t_last, horizon) < horizon)
+        with jax.named_scope("weave"):
+            (queue, banks, t_last, st, tacc, tstate), (live, cmds) = \
+                jax.lax.scan(ebody, (queue, banks, t0 * (start - 1), acc0,
+                                     tacc0, tstate),
+                             jnp.arange(cfg.event_budget(), dtype=jnp.int32))
+            # the binding constraint is the busiest channel's event count
+            weave_events = jnp.max(jnp.sum(live.astype(jnp.int32), axis=0))
+            # budget exhausted with events still pending anywhere before
+            # the static horizon: spilled events replay next window
+            # (graceful) and the window is flagged — never silent.  The
+            # check runs against `horizon`, not `end`: a pending *tail*
+            # event (an arrival in [end, horizon)) carries a drain-
+            # hysteresis update the dense scan's inactive ticks would
+            # have applied, so skipping it must flag too, or the sat=0
+            # => bit-identical contract (relied on by `mess._run_mix`
+            # and `traces.replay._replay_exact`) would leak a silent
+            # divergence into the next window.
+            weave_sat = jnp.any(
+                nev_fn(queue, banks, t_last, horizon) < horizon)
 
     n_rd = jnp.sum(st.served_rd)
     sum_if = jnp.sum(st.sum_if_lat_ps)
@@ -305,6 +323,30 @@ def _window_step(cfg: StageConfig, clock: ClockModel, wcfg: WorkloadConfig,
     return (queue, banks, fstate, l_ir_next, lat_est, tstate), (out, diag)
 
 
+def count_launch(cfg: StageConfig, rows: int, reruns: int = 0,
+                 weave_events=None) -> None:
+    """Count one compiled launch of ``rows`` points on ``cfg``'s engine.
+
+    Records the `repro.obs.spans` counters the grid drivers report per
+    launch: ``repro.rows.<engine>`` (rows routed to it, less the
+    ``reruns`` that are dense re-runs of saturated event rows, counted
+    as ``repro.rows.rerun``), ``repro.steps.launched`` (rows x windows x
+    the engine's static scan steps) and, for the event engine,
+    ``repro.steps.event_used`` / ``repro.steps.event_budget``: the
+    post-warm-up ``weave_events`` of the launched rows against the
+    budget steps they scanned.
+    """
+    spans.count(f"repro.rows.{cfg.weave}", rows - reruns)
+    if reruns:
+        spans.count("repro.rows.rerun", reruns)
+    spans.count("repro.steps.launched", rows * cfg.windows * cfg.scan_steps())
+    if cfg.weave == "event":
+        spans.count("repro.steps.event_used",
+                    np.sum(weave_events, dtype=np.int64))
+        spans.count("repro.steps.event_budget", rows * cfg.event_budget()
+                    * (cfg.windows - cfg.warmup))
+
+
 def run_frontend(cfg: StageConfig, frontend):
     """Simulate the platform driven by any bound-phase frontend.
 
@@ -343,7 +385,8 @@ def run_frontend(cfg: StageConfig, frontend):
               dram.init_tele(cfg.platform.dram) if cfg.telemetry else None)
     _, (outs, diag) = jax.lax.scan(
         step, carry0, jnp.arange(cfg.windows, dtype=jnp.int32))
-    return _aggregate(cfg, outs, diag), outs
+    with jax.named_scope("aggregate"):
+        return _aggregate(cfg, outs, diag), outs
 
 
 def run_point(cfg: StageConfig, pace, wr_num):

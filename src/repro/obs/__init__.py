@@ -17,6 +17,9 @@ turns the platform's in-kernel telemetry planes (enabled with
 * `repro.obs.perspectives` — per-window rank correlation between the
   three views' latency/progress series: the machine-readable
   "perspectives diverge, corrections re-couple them" report.
+* `repro.obs.spans` — not simulated time but the simulator's own: host
+  spans and launch counters that the grid drivers record (always on,
+  a bounded ring), also visible in a `jax.profiler` trace.
 
 Telemetry is a **static** `StageConfig` flag: when off (default) the
 traced computation is exactly the historical graph — bit-identical

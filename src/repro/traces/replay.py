@@ -33,8 +33,9 @@ import functools
 import jax
 import numpy as np
 
-from repro.core.platform import StageConfig, run_frontend
+from repro.core.platform import StageConfig, count_launch, run_frontend
 from repro.core.shard import sharded_vmap
+from repro.obs import spans
 from repro.traces.frontend import TraceFrontend
 from repro.traces.mix import TraceMix
 from repro.traces.trace import Trace
@@ -52,7 +53,9 @@ def _replay_fn(cfg: StageConfig, donate: bool = False):
         views, outs = run_frontend(cfg, TraceFrontend(
             trace, cfg.workload_config()))
         out = dict({k: views[k] for k in VIEW_KEYS},
-                   weave_sat=views["weave_sat"], progress=outs.progress)
+                   weave_sat=views["weave_sat"],
+                   weave_events=views["weave_events"],
+                   progress=outs.progress)
         if cfg.telemetry:
             # three-perspective telemetry planes (`repro.obs`): full
             # (W, ...) per-window series, flat keys so the batch axis
@@ -75,17 +78,29 @@ def _replay_exact(cfg: StageConfig, batch, donate: bool) -> dict:
     buffers are consumed by the first pass, so the fallback is
     unavailable — saturated rows stay flagged in ``weave_sat`` for the
     caller to handle (pre-verify the regime, or keep the default
-    ``donate=False``).
+    ``donate=False``).  Each launch is recorded under a
+    `repro.obs.spans` span and counted by `platform.count_launch`;
+    the first pass's ``weave_events`` only feed those counters.
     """
-    out = jax.device_get(_replay_fn(cfg, donate)(batch))
+    with spans.span(f"repro.replay.{cfg.weave}"):
+        res = _replay_fn(cfg, donate)(batch)
+        with spans.span(f"repro.replay.{cfg.weave}.fetch"):
+            out = jax.device_get(res)
     out = {k: np.array(v) for k, v in out.items()}
+    count_launch(cfg, len(out["weave_sat"]),
+                 weave_events=out.pop("weave_events"))
     sat = np.flatnonzero(out["weave_sat"] > 0)
     if sat.size and cfg.weave == "event" and not donate:
         import dataclasses
 
         cfg_dense = dataclasses.replace(cfg, weave="dense")
-        sub = jax.tree_util.tree_map(lambda a: a[sat], batch)
-        fixed = jax.device_get(_replay_fn(cfg_dense, False)(sub))
+        with spans.span("repro.replay.dense"):
+            sub = jax.tree_util.tree_map(lambda a: a[sat], batch)
+            res = _replay_fn(cfg_dense, False)(sub)
+            with spans.span("repro.replay.dense.fetch"):
+                fixed = jax.device_get(res)
+        count_launch(cfg_dense, sat.size, reruns=sat.size)
+        fixed.pop("weave_events")
         for k, v in fixed.items():
             if k != "weave_sat":           # keep the diagnostic flag
                 out[k][sat] = np.asarray(v)
@@ -118,6 +133,7 @@ def _runtime_windows(progress, target, pos0=None):
     return np.where(target > 0, rt, 0.0), any_done | (target == 0)
 
 
+@spans.span("repro.replay.suite")
 def replay_suite(cfg: StageConfig, traces: Trace,
                  donate: bool = False) -> dict:
     """Replay a stacked trace batch through one stage; host-side dict.
@@ -139,10 +155,12 @@ def replay_suite(cfg: StageConfig, traces: Trace,
     """
     wcfg = cfg.workload_config()
     # host-side fields first: after a donating call the buffers are gone
-    length = np.asarray(jax.device_get(traces.length))  # (A,)
-    # per-core regions must stay below the chase-probe region (bit 31):
-    # with two sockets (48 cores) large footprints can reach it
-    fmax = int(np.max(np.asarray(jax.device_get(traces.footprint_lines))))
+    with spans.span("repro.replay.inputs"):
+        length = np.asarray(jax.device_get(traces.length))  # (A,)
+        # per-core regions must stay below the chase-probe region (bit
+        # 31): with two sockets (48 cores) large footprints can reach it
+        fmax = int(np.max(np.asarray(
+            jax.device_get(traces.footprint_lines))))
     if wcfg.n_cores * fmax > 1 << 31:
         raise ValueError(
             f"{wcfg.n_cores} cores x footprint {fmax} lines overflows "
@@ -150,22 +168,24 @@ def replay_suite(cfg: StageConfig, traces: Trace,
             f"region starts at bit 31); shrink the footprint")
 
     out = _replay_exact(cfg, traces, donate)
-    progress = np.asarray(out.pop("progress"))       # (A, W, n_cores)
-    out = {k: np.asarray(v) for k, v in out.items()}
-    cid = np.arange(wcfg.n_cores)
-    target = np.where(cid[None, :] < wcfg.n_traffic,
-                      length[:, None], 0)             # (A, n_cores)
-    rt, done = _runtime_windows(progress, target)
-    traffic = cid < wcfg.n_traffic
-    # the app finishes when its slowest core does (lockstep in solo mode)
-    runtime_windows = rt[:, traffic].max(axis=1)
+    with spans.span("repro.replay.runtime"):
+        progress = np.asarray(out.pop("progress"))   # (A, W, n_cores)
+        out = {k: np.asarray(v) for k, v in out.items()}
+        cid = np.arange(wcfg.n_cores)
+        target = np.where(cid[None, :] < wcfg.n_traffic,
+                          length[:, None], 0)         # (A, n_cores)
+        rt, done = _runtime_windows(progress, target)
+        traffic = cid < wcfg.n_traffic
+        # the app finishes when its slowest core does (lockstep in solo
+        # mode)
+        runtime_windows = rt[:, traffic].max(axis=1)
 
-    cpu = cfg.platform.cpu
-    window_ms = cpu.window_cycles * cpu.cpu_ps_per_clk * 1e-9
-    out["done"] = done[:, traffic].all(axis=1)
-    out["runtime_windows"] = runtime_windows
-    out["runtime_ms"] = runtime_windows * window_ms
-    out["progress_final"] = progress[:, -1, :][:, traffic].min(axis=1)
+        cpu = cfg.platform.cpu
+        window_ms = cpu.window_cycles * cpu.cpu_ps_per_clk * 1e-9
+        out["done"] = done[:, traffic].all(axis=1)
+        out["runtime_windows"] = runtime_windows
+        out["runtime_ms"] = runtime_windows * window_ms
+        out["progress_final"] = progress[:, -1, :][:, traffic].min(axis=1)
     return out
 
 
@@ -187,6 +207,7 @@ def replay_mix(cfg: StageConfig, mix: TraceMix) -> dict:
     return jax.tree_util.tree_map(lambda a: a[0], out)
 
 
+@spans.span("repro.replay.mixes")
 def replay_mixes(cfg: StageConfig, mixes: TraceMix,
                  donate: bool = False) -> dict:
     """Replay a stack of mixes (leading mix axis, device-sharded).
@@ -203,33 +224,35 @@ def replay_mixes(cfg: StageConfig, mixes: TraceMix,
         the batch (`nan` / False padding for mixes with fewer apps).
     """
     # host-side fields first: after a donating call the buffers are gone
-    target = np.asarray(jax.device_get(mixes.length))   # (M, n_cores)
-    app_id = np.asarray(jax.device_get(mixes.app_id))   # (M, n_cores)
-    pos0 = np.asarray(jax.device_get(mixes.pos0))       # (M, n_cores)
+    with spans.span("repro.replay.inputs"):
+        target = np.asarray(jax.device_get(mixes.length))  # (M, n_cores)
+        app_id = np.asarray(jax.device_get(mixes.app_id))  # (M, n_cores)
+        pos0 = np.asarray(jax.device_get(mixes.pos0))      # (M, n_cores)
     out = _replay_exact(cfg, mixes, donate)
-    progress = np.asarray(out.pop("progress"))       # (M, W, n_cores)
+    with spans.span("repro.replay.runtime"):
+        progress = np.asarray(out.pop("progress"))   # (M, W, n_cores)
 
-    rt, done = _runtime_windows(progress, target, pos0)
-    cpu = cfg.platform.cpu
-    window_ms = cpu.window_cycles * cpu.cpu_ps_per_clk * 1e-9
+        rt, done = _runtime_windows(progress, target, pos0)
+        cpu = cfg.platform.cpu
+        window_ms = cpu.window_cycles * cpu.cpu_ps_per_clk * 1e-9
 
-    M = app_id.shape[0]
-    n_apps = int(app_id.max()) + 1 if app_id.size else 0
-    app_rt = np.full((M, n_apps), np.nan)
-    app_done = np.zeros((M, n_apps), bool)
-    for m in range(M):
-        for a in range(n_apps):
-            cores = app_id[m] == a
-            if cores.any():
-                # an app finishes when its slowest core does
-                app_rt[m, a] = rt[m, cores].max()
-                app_done[m, a] = done[m, cores].all()
+        M = app_id.shape[0]
+        n_apps = int(app_id.max()) + 1 if app_id.size else 0
+        app_rt = np.full((M, n_apps), np.nan)
+        app_done = np.zeros((M, n_apps), bool)
+        for m in range(M):
+            for a in range(n_apps):
+                cores = app_id[m] == a
+                if cores.any():
+                    # an app finishes when its slowest core does
+                    app_rt[m, a] = rt[m, cores].max()
+                    app_done[m, a] = done[m, cores].all()
 
-    out["core_runtime_windows"] = rt
-    out["core_done"] = done
-    out["app_runtime_windows"] = app_rt
-    out["app_runtime_ms"] = app_rt * window_ms
-    out["app_done"] = app_done
+        out["core_runtime_windows"] = rt
+        out["core_done"] = done
+        out["app_runtime_windows"] = app_rt
+        out["app_runtime_ms"] = app_rt * window_ms
+        out["app_done"] = app_done
     return out
 
 
