@@ -21,9 +21,13 @@ static shapes:
                      (row-hit >> activate >> precharge, oldest first),
 * FAW sliding window -> a 4-deep shift register of ACT timestamps.
 
-The same tick step has a Pallas TPU kernel twin
-(`repro.kernels.bank_timing`) for the eligibility+select hot loop; this
-module is the pure-jnp reference semantics (`ref.py` delegates here).
+Per-entry reads of per-bank state, and the writes of the selected
+command, go through one-hot match planes against static bank and slot
+indices (`_match`): a dense select-reduce in place of an indexed
+gather or scatter, which the TPU runs one index at a time.
+
+`repro.kernels.bank_timing` is a Pallas kernel of the FR-FCFS select
+alone, off the main path; `tick` does not call it.
 """
 from __future__ import annotations
 
@@ -55,10 +59,9 @@ class BankPlanes(NamedTuple):
 
     These are pure functions of `DramParams` (never of simulation
     state), so they are built **once** per device — host-side numpy, so
-    they embed as XLA constants — instead of being re-derived with
-    ``jnp.arange`` on every `tick` / `next_event` trace.  Both weave
-    engines (the dense per-tick scan and the event-horizon scan) share
-    one instance via `bank_planes`.
+    they embed as XLA constants — instead of being re-derived on every
+    `tick` trace.  Both weave engines (the dense per-tick scan and the
+    event-horizon scan) share one instance via `bank_planes`.
     """
 
     cidx: np.ndarray          # (C,)  channel index
@@ -296,7 +299,7 @@ def init_banks(dram: DramParams) -> BankState:
     """All banks precharged, refresh deadlines staggered across ranks.
 
     Also builds (and caches) the device's `BankPlanes` — the
-    loop-invariant index planes both weave engines gather against.
+    loop-invariant index planes `tick` compares against.
     """
     bank_planes(dram)            # warm the per-device plane cache
     C = dram.n_channels
@@ -321,9 +324,35 @@ def init_banks(dram: DramParams) -> BankState:
     )
 
 
-def _gather(bank_field, fbank):
-    """(C, RB) field gathered per queue entry -> (C, Q)."""
-    return jnp.take_along_axis(bank_field, fbank, axis=1)
+def _match(idx, n: int):
+    """One-hot match plane of an index ``(C, ...)`` against ``arange(n)``:
+    ``(C,) -> (C, n)`` and ``(C, Q) -> (C, n, Q)`` (the matched axis
+    second, so a queue's slots stay on the minor axis)."""
+    iota = jnp.arange(n, dtype=jnp.int32)
+    if idx.ndim == 1:
+        return idx[:, None] == iota
+    return idx[:, None, :] == iota[:, None]
+
+
+def _select(match, values, axis: int):
+    """Reduce ``values`` over ``axis`` to where the one-hot ``match`` is
+    set: a select-sum (select-any for bools).  Where exactly one index
+    matches, that is the indexed read bit for bit."""
+    if values.dtype == jnp.bool_:
+        return jnp.any(match & values, axis=axis)
+    return jnp.sum(jnp.where(match, values, 0), axis=axis,
+                   dtype=values.dtype)
+
+
+def _gather(bank_field, match):
+    """(C, RB) per-bank field read per queue entry -> (C, Q).
+
+    ``match`` is the queue's ``(C, RB, Q)`` bank-match plane
+    (`_match(queue.fbank, RB)`).  Every slot holds a flat bank in
+    ``[0, RB)`` (empty slots hold 0, `init_queue`), so exactly one bank
+    matches each slot.
+    """
+    return _select(match, bank_field[:, :, None], axis=1)
 
 
 def tick(queue: QueueState, banks: BankState, t, *,
@@ -351,15 +380,15 @@ def tick(queue: QueueState, banks: BankState, t, *,
         planes: the device's precomputed `BankPlanes`; defaults to the
             cached `bank_planes(dram)`.
         telemetry: **static** flag; when False (default) the traced
-            computation is exactly the historical tick graph.  When
-            True, the tick additionally returns its `TickTele`
-            increments and the threaded `TeleState`.
+            graph is the flags-off tick graph, which the flag's code
+            does not alter.  When True, the tick additionally returns
+            its `TickTele` increments and the threaded `TeleState`.
         tele: the telemetry carry (`TeleState`); only read with
             ``telemetry=True``.
         cmd_trace: **static** flag; when True the tick additionally
             returns its `TickCmd` command record (the `repro.oracle`
-            recorder).  Like ``telemetry``, the False path traces
-            exactly the historical graph.
+            recorder).  Like ``telemetry``, the False path traces the
+            flags-off graph unaltered.
 
     Returns:
         ``(queue', banks', TickStats)``; ``telemetry=True`` appends
@@ -369,10 +398,10 @@ def tick(queue: QueueState, banks: BankState, t, *,
         picoseconds (interface view).
     """
     C = dram.n_channels
+    RB = dram.banks_per_channel
     nbanks = dram.banks_per_rank
     if planes is None:
         planes = bank_planes(dram)
-    cidx = planes.cidx
     t = jnp.broadcast_to(jnp.asarray(t, jnp.int32), (C,))
     active = jnp.broadcast_to(jnp.asarray(active), (C,))
     t_r = t[:, None]                    # against (C, R) / (C, RB) / (C, Q)
@@ -409,40 +438,39 @@ def tick(queue: QueueState, banks: BankState, t, *,
     banks = banks._replace(drain=drain)
 
     # ---- per-entry eligibility ---------------------------------------
-    open_e = _gather(banks.open_row, queue.fbank)
-    nact_e = _gather(banks.next_act, queue.fbank)
-    nrd_e = _gather(banks.next_rd, queue.fbank)
-    nwr_e = _gather(banks.next_wr, queue.fbank)
-    npre_e = _gather(banks.next_pre, queue.fbank)
-    rank_e = queue.fbank // nbanks                              # (C, Q)
+    # Bank timers are compared at the bank, then read per entry through
+    # the queue's bank-match plane.
+    match = _match(queue.fbank, RB)                             # (C, RB, Q)
+    open_e = _gather(banks.open_row, match)
+    faw_ok = jnp.repeat(t_r >= banks.faw[:, :, 0] + dram.tFAW,
+                        nbanks, axis=1)                         # (C, RB)
 
     row_hit = open_e == queue.row
     closed = open_e < 0
     is_wr = queue.is_write == 1
     bus_ok = (t >= banks.bus_free)[:, None]
-    faw_ok_rank = t_r >= banks.faw[:, :, 0] + dram.tFAW         # (C, R)
-    faw_ok_e = jnp.take_along_axis(faw_ok_rank, rank_e, axis=1)
     drain_c = drain[:, None]
 
     # During a drain the channel is dedicated to writes; outside it,
     # to reads (standard watermark write-buffering).
     side_ok = jnp.where(is_wr, drain_c, ~drain_c)
-    elig_rd = (arrived & ~is_wr & row_hit & (t_r >= nrd_e) & bus_ok
+    elig_rd = (arrived & ~is_wr & row_hit
+               & _gather(t_r >= banks.next_rd, match) & bus_ok
                & (t >= banks.wtr_until)[:, None] & ~drain_c)
-    elig_wr = (arrived & is_wr & row_hit & (t_r >= nwr_e) & bus_ok
+    elig_wr = (arrived & is_wr & row_hit
+               & _gather(t_r >= banks.next_wr, match) & bus_ok
                & (t >= banks.rtw_until)[:, None] & drain_c)
-    elig_act = arrived & closed & (t_r >= nact_e) & faw_ok_e & side_ok
+    elig_act = (arrived & closed & side_ok
+                & _gather((t_r >= banks.next_act) & faw_ok, match))
 
     # FR-FCFS guard: don't precharge a row that still has pending hits
     # *on the active side* — during a write drain only write hits count
     # (a pending read hit must not block the drain's precharges, or the
     # drain can never finish and the channel deadlocks).
-    hit_pend = jnp.zeros(
-        (C, dram.banks_per_channel), bool).at[cidx[:, None], queue.fbank].max(
-        arrived & row_hit & (is_wr == drain_c))
-    hit_pend_e = _gather(hit_pend, queue.fbank)
-    elig_pre = (arrived & ~closed & ~row_hit & (t_r >= npre_e)
-                & ~hit_pend_e & side_ok)
+    pend = arrived & row_hit & (is_wr == drain_c)
+    hit_pend = _select(match, pend[:, None, :], axis=2)         # (C, RB)
+    elig_pre = (arrived & ~closed & ~row_hit & side_ok
+                & _gather((t_r >= banks.next_pre) & ~hit_pend, match))
 
     # ---- FR-FCFS priority: CAS > ACT > PRE, oldest-first --------------
     age = _BIG - queue.arrival
@@ -458,11 +486,11 @@ def tick(queue: QueueState, banks: BankState, t, *,
     score = jnp.where(active[:, None], score, 0)
 
     sel = jnp.argmax(score, axis=1)                             # (C,)
-    sel_score = jnp.take_along_axis(score, sel[:, None], 1)[:, 0]
-    any_cmd = sel_score > 0
+    any_cmd = jnp.max(score, axis=1) > 0                        # score[sel]
+    sel_match = _match(sel, queue.valid.shape[1])               # (C, Q)
 
     def pick(field):
-        return jnp.take_along_axis(field, sel[:, None], 1)[:, 0]
+        return _select(sel_match, field, axis=1)
 
     s_fb = pick(queue.fbank)
     s_row = pick(queue.row)
@@ -470,12 +498,12 @@ def tick(queue: QueueState, banks: BankState, t, *,
     s_issue = pick(queue.issue_cycle)
     s_rank = s_fb // nbanks
     s_bg = (s_fb % nbanks) // dram.banks_per_group
-    s_iswr = pick(is_wr.astype(jnp.int32)) == 1
+    s_iswr = pick(is_wr)
     s_chase = pick(queue.is_chase) == 1
-    s_rd_ok = pick(elig_rd.astype(jnp.int32)) == 1
-    s_wr_ok = pick(elig_wr.astype(jnp.int32)) == 1
-    s_act_ok = pick(elig_act.astype(jnp.int32)) == 1
-    s_pre_ok = pick(elig_pre.astype(jnp.int32)) == 1
+    s_rd_ok = pick(elig_rd)
+    s_wr_ok = pick(elig_wr)
+    s_act_ok = pick(elig_act)
+    s_pre_ok = pick(elig_pre)
     if policy.row_hit_cap > 0:
         capped1 = banks.hit_streak >= policy.row_hit_cap
         # under the cap inversion an ACT can outrank CAS; recompute cmd
@@ -489,26 +517,23 @@ def tick(queue: QueueState, banks: BankState, t, *,
     s_wr = s_cas & s_iswr
 
     # ---- apply the selected command per channel ----------------------
-    bsel = (cidx, s_fb)
+    # writes to the selected bank: a select against its one-hot row
+    bsel = _match(s_fb, RB)                                     # (C, RB)
+    on_act = bsel & s_act[:, None]
 
     # ACT
     same_rank = planes.rank_of[None, :] == s_rank[:, None]
     same_grp = (planes.grp_of[None, :] == s_bg[:, None]) & same_rank
-    open_row = banks.open_row.at[bsel].set(
-        jnp.where(s_act, s_row, banks.open_row[bsel]))
+    open_row = jnp.where(on_act, s_row[:, None], banks.open_row)
     nact = jnp.where(s_act[:, None] & same_rank,
                      jnp.maximum(banks.next_act, t_r + dram.tRRD_S),
                      banks.next_act)
     nact = jnp.where(s_act[:, None] & same_grp,
                      jnp.maximum(nact, t_r + dram.tRRD_L), nact)
-    nact = nact.at[bsel].set(
-        jnp.where(s_act, jnp.maximum(nact[bsel], t + dram.tRC), nact[bsel]))
-    nrd = banks.next_rd.at[bsel].set(
-        jnp.where(s_act, t + dram.tRCD, banks.next_rd[bsel]))
-    nwr = banks.next_wr.at[bsel].set(
-        jnp.where(s_act, t + dram.tRCD, banks.next_wr[bsel]))
-    npre = banks.next_pre.at[bsel].set(
-        jnp.where(s_act, t + dram.tRAS, banks.next_pre[bsel]))
+    nact = jnp.where(on_act, jnp.maximum(nact, t_r + dram.tRC), nact)
+    nrd = jnp.where(on_act, t_r + dram.tRCD, banks.next_rd)
+    nwr = jnp.where(on_act, t_r + dram.tRCD, banks.next_wr)
+    npre = jnp.where(on_act, t_r + dram.tRAS, banks.next_pre)
     # FAW shift-register push
     faw_new = jnp.concatenate(
         [banks.faw[:, :, 1:],
@@ -526,21 +551,21 @@ def tick(queue: QueueState, banks: BankState, t, *,
     ccd = jnp.where(same_grp, dram.tCCD_L, dram.tCCD_S)
     nrd = jnp.where(s_cas[:, None], jnp.maximum(nrd, t_r + ccd), nrd)
     nwr = jnp.where(s_cas[:, None], jnp.maximum(nwr, t_r + ccd), nwr)
-    npre = npre.at[bsel].set(jnp.where(
-        s_rd, jnp.maximum(npre[bsel], t + dram.tRTP),
-        jnp.where(s_wr, jnp.maximum(npre[bsel],
-                                    t + dram.tCWL + dram.tBL + dram.tWR),
-                  npre[bsel])))
+    npre = jnp.where(bsel & s_rd[:, None],
+                     jnp.maximum(npre, t_r + dram.tRTP),
+                     jnp.where(bsel & s_wr[:, None],
+                               jnp.maximum(npre, t_r + dram.tCWL + dram.tBL
+                                           + dram.tWR),
+                               npre))
     wtr_until = jnp.where(s_wr, t + dram.tCWL + dram.tBL + dram.tWTR_L,
                           banks.wtr_until)
     rtw_until = jnp.where(s_rd, t + dram.tCL + dram.tBL + dram.tRTRS
                           - dram.tCWL, banks.rtw_until)
 
     # PRE
-    open_row = open_row.at[bsel].set(
-        jnp.where(s_pre, -1, open_row[bsel]))
-    nact = nact.at[bsel].set(
-        jnp.where(s_pre, jnp.maximum(nact[bsel], t + dram.tRP), nact[bsel]))
+    on_pre = bsel & s_pre[:, None]
+    open_row = jnp.where(on_pre, -1, open_row)
+    nact = jnp.where(on_pre, jnp.maximum(nact, t_r + dram.tRP), nact)
 
     hit_streak = jnp.where(s_cas, banks.hit_streak + 1,
                            jnp.where(any_cmd, 0, banks.hit_streak))
@@ -553,8 +578,7 @@ def tick(queue: QueueState, banks: BankState, t, *,
                       hit_streak=hit_streak)
 
     # retire CAS'd entries
-    served = jnp.zeros_like(queue.valid).at[cidx, sel].set(
-        s_cas.astype(jnp.int32))
+    served = (sel_match & s_cas[:, None]).astype(jnp.int32)
     queue = queue._replace(valid=queue.valid & (1 - served))
 
     # ---- stats --------------------------------------------------------
@@ -576,8 +600,8 @@ def tick(queue: QueueState, banks: BankState, t, *,
 
     extras = ()
     if telemetry:
-        # ---- telemetry counter planes (static flag: the path above is
-        # the untouched historical graph when telemetry is off) --------
+        # ---- telemetry counter planes (static flag: with telemetry
+        # off, the path above is the whole graph) ----------------------
         # Everything is accounted at *events* (command grants, refresh
         # deadlines, row closes), never sampled per tick, so the planes
         # are engine-invariant: the event-horizon scan evaluates
@@ -591,9 +615,8 @@ def tick(queue: QueueState, banks: BankState, t, *,
         # safe).
         busy = jnp.where(refmask & (open_row_pre >= 0),
                          t_r - tele.opened_at, 0)
-        opened_at = tele.opened_at.at[bsel].set(
-            jnp.where(s_act, t, tele.opened_at[bsel]))
-        busy = busy.at[bsel].add(jnp.where(s_pre, t - opened_at[bsel], 0))
+        opened_at = jnp.where(on_act, t_r, tele.opened_at)
+        busy = busy + jnp.where(on_pre, t_r - opened_at, 0)
         # write-drain planes at CAS resolution: a maximal run of write
         # CAS grants (uninterrupted by a read CAS) is one drain service
         # burst, and its dwell — span from first to last write grant,
@@ -612,10 +635,10 @@ def tick(queue: QueueState, banks: BankState, t, *,
         # interface view in CPU-perceived picoseconds (the int behind
         # sum_if_lat_ps)
         one_rd = s_rd.astype(jnp.int32)
-        hist_rd = jnp.zeros((C, N_HIST), jnp.int32).at[
-            cidx, log2_bucket(rd_lat)].add(one_rd)
-        hist_if = jnp.zeros((C, N_HIST), jnp.int32).at[
-            cidx, log2_bucket(if_lat_i)].add(one_rd)
+        hist_rd = (_match(log2_bucket(rd_lat), N_HIST)
+                   & s_rd[:, None]).astype(jnp.int32)
+        hist_if = (_match(log2_bucket(if_lat_i), N_HIST)
+                   & s_rd[:, None]).astype(jnp.int32)
         tele_inc = TickTele(
             n_act=s_act.astype(jnp.int32), n_pre=s_pre.astype(jnp.int32),
             n_cas_rd=one_rd, n_cas_wr=s_wr.astype(jnp.int32),
@@ -643,8 +666,7 @@ def tick(queue: QueueState, banks: BankState, t, *,
 
 
 def next_event(queue: QueueState, banks: BankState, t, end, *,
-               dram: DramParams, policy: SchedulerPolicy,
-               planes: BankPlanes | None = None):
+               dram: DramParams, policy: SchedulerPolicy):
     """The exact event horizon: earliest tick > ``t`` where `tick` can act.
 
     Evaluated on the *post-tick* state at ``t``, this returns — **per
@@ -683,17 +705,12 @@ def next_event(queue: QueueState, banks: BankState, t, end, *,
         end: static scan horizon (``window start + ticks_per_window``);
             results are clamped into ``[t + 1, end]`` — ``end`` means
             "no event on this channel before the horizon".
-    dram, policy: static device timings + controller flavor.
-        planes: the device's precomputed `BankPlanes`; defaults to the
-            cached `bank_planes(dram)`.
+        dram, policy: static device timings + controller flavor.
 
     Returns:
         ``(C,)`` int32 per-channel next-event ticks in ``[t + 1, end]``.
     """
     nbanks = dram.banks_per_rank
-    if planes is None:
-        planes = bank_planes(dram)
-    cidx = planes.cidx
     t = jnp.broadcast_to(jnp.asarray(t, jnp.int32), (dram.n_channels,))
     t_r = t[:, None]
 
@@ -719,8 +736,9 @@ def next_event(queue: QueueState, banks: BankState, t, end, *,
     drain_c = drain[:, None]
 
     # ---- per-entry command readiness ----------------------------------
-    open_e = _gather(banks.open_row, queue.fbank)
-    rank_e = queue.fbank // nbanks
+    # per-bank fields read per entry through the bank-match plane (`tick`)
+    match = _match(queue.fbank, dram.banks_per_channel)         # (C, RB, Q)
+    open_e = _gather(banks.open_row, match)
     row_hit = open_e == queue.row
     closed = open_e < 0
     side_ok = jnp.where(is_wr, drain_c, ~drain_c)
@@ -728,30 +746,26 @@ def next_event(queue: QueueState, banks: BankState, t, end, *,
     # CAS: bank CAS timer + shared bus + write/read turnaround
     cas_ready = jnp.where(
         is_wr,
-        jnp.maximum(_gather(banks.next_wr, queue.fbank),
-                    banks.rtw_until[:, None]),
-        jnp.maximum(_gather(banks.next_rd, queue.fbank),
-                    banks.wtr_until[:, None]))
+        jnp.maximum(_gather(banks.next_wr, match), banks.rtw_until[:, None]),
+        jnp.maximum(_gather(banks.next_rd, match), banks.wtr_until[:, None]))
     cas_ready = jnp.maximum(cas_ready, banks.bus_free[:, None])
     ev = jnp.minimum(ev, jnp.min(jnp.where(
         arrived & row_hit & side_ok, cas_ready, _BIG), axis=1))
 
     # ACT: bank ACT timer + the rank's FAW sliding-window expiry
-    faw_ready = banks.faw[:, :, 0] + dram.tFAW                  # (C, R)
-    act_ready = jnp.maximum(_gather(banks.next_act, queue.fbank),
-                            jnp.take_along_axis(faw_ready, rank_e, axis=1))
+    faw_ready = jnp.repeat(banks.faw[:, :, 0] + dram.tFAW, nbanks,
+                           axis=1)                              # (C, RB)
+    act_ready = _gather(jnp.maximum(banks.next_act, faw_ready), match)
     ev = jnp.minimum(ev, jnp.min(jnp.where(
         arrived & closed & side_ok, act_ready, _BIG), axis=1))
 
-    # PRE: row conflict with no pending same-side hits on the bank
-    hit_pend = jnp.zeros(
-        (dram.n_channels, dram.banks_per_channel),
-        bool).at[cidx[:, None], queue.fbank].max(
-        arrived & row_hit & (is_wr == drain_c))
-    elig_pre = (arrived & ~closed & ~row_hit & side_ok
-                & ~_gather(hit_pend, queue.fbank))
+    # PRE: row conflict with no pending same-side hits on the bank (a
+    # bank with pending hits reads "never", like an ineligible entry)
+    pend = arrived & row_hit & (is_wr == drain_c)
+    hit_pend = _select(match, pend[:, None, :], axis=2)         # (C, RB)
+    pre_ready = _gather(jnp.where(hit_pend, _BIG, banks.next_pre), match)
     ev = jnp.minimum(ev, jnp.min(jnp.where(
-        elig_pre, _gather(banks.next_pre, queue.fbank), _BIG), axis=1))
+        arrived & ~closed & ~row_hit & side_ok, pre_ready, _BIG), axis=1))
 
     # ---- candidate: refresh deadlines ---------------------------------
     ev = jnp.minimum(ev, jnp.min(banks.next_ref, axis=1))

@@ -90,15 +90,16 @@ class StageConfig:
     #: log2 latency histograms (`dram.TickTele`) and the window step
     #: samples interface-view series (queue depth, MSHR budget, PI
     #: estimate), all emitted as ``tele_*`` keys in the views.  Static
-    #: flag, off by default: the False path traces the exact historical
-    #: graph, so all outputs stay bit-identical and free when off.
+    #: flag, off by default: the False path traces the flags-off graph,
+    #: which the flag's code does not alter, so all outputs stay
+    #: bit-identical and free when off.
     telemetry: bool = False
     #: command-stream recorder (`repro.oracle`): when True, every weave
     #: step also emits the granted DRAM command (`dram.TickCmd` — code,
     #: grant tick, bank, row, refresh firings) as ``cmd_*`` keys in the
     #: views, ready for `repro.oracle.extract_stream` and the protocol-
     #: legality checker.  Static flag like ``telemetry``: the False
-    #: path traces the exact historical graph, and because both weave
+    #: path traces the flags-off graph unaltered, and because both weave
     #: engines evaluate exactly the grant ticks, the recorded streams
     #: are engine-invariant.
     cmd_trace: bool = False
@@ -196,7 +197,7 @@ def _window_step(cfg: StageConfig, clock: ClockModel, wcfg: WorkloadConfig,
     # Both scan bodies below are written once for all four flag
     # combinations: `None` is an *empty* pytree node, so a disabled
     # flag's carry slot / ys slot contributes no leaves and the traced
-    # graph is exactly the historical flags-off one.
+    # graph is exactly the flags-off one.
     def split_extras(rest):
         """Unpack `dram.tick`'s flag-dependent return tail."""
         ti = ts = cmd = None
@@ -232,8 +233,7 @@ def _window_step(cfg: StageConfig, clock: ClockModel, wcfg: WorkloadConfig,
         # like the dense scan's inactive tail ticks.
         horizon = start + clock.ticks_per_window_static
         nev_fn = functools.partial(
-            dram.next_event, dram=cfg.platform.dram, policy=cfg.policy,
-            planes=planes)
+            dram.next_event, dram=cfg.platform.dram, policy=cfg.policy)
         t0 = jnp.full((cfg.platform.dram.n_channels,), 1, jnp.int32)
 
         def ebody(qbta, i):
@@ -380,7 +380,7 @@ def run_frontend(cfg: StageConfig, frontend):
 
     step = functools.partial(_window_step, cfg, clock, wcfg, frontend)
     # the trailing telemetry-state slot is None (an empty pytree node)
-    # when telemetry is off, keeping the flags-off graph historical
+    # when telemetry is off, leaving the flags-off graph unaltered
     carry0 = (queue, banks, fstate, l_ir0, lat_est0,
               dram.init_tele(cfg.platform.dram) if cfg.telemetry else None)
     _, (outs, diag) = jax.lax.scan(

@@ -12,6 +12,7 @@ process may load the TPU library at a time, and every test worker
 imports this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -59,6 +60,62 @@ def _pace_batch(sharding):
     s = jax.ShapeDtypeStruct((len(DEFAULT_PACES),), jnp.int32,
                              sharding=sharding)
     return (s, s)
+
+
+def _computations(hlo_text):
+    """Compiled HLO text -> {computation name: its instruction lines}."""
+    comps, name = {}, None
+    for line in hlo_text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) .*\{$", line)
+        if head and not line.startswith(" "):
+            name = head.group(1)
+            comps[name] = []
+        elif name is not None and line.startswith(" "):
+            comps[name].append(line)
+    return comps
+
+
+def _reachable(comps, roots):
+    """The named computations and every computation they call."""
+    seen, todo = set(), list(roots)
+    while todo:
+        name = todo.pop()
+        if name in seen:
+            continue
+        seen.add(name)
+        for line in comps[name]:
+            todo += re.findall(
+                r"(?:calls|to_apply|body|condition)=%([\w.\-]+)", line)
+            for group in re.findall(r"(?:branch|called)_computations="
+                                    r"\{([^}]*)\}", line):
+                todo += re.findall(r"%([\w.\-]+)", group)
+    return seen
+
+
+@pytest.mark.parametrize("weave,points", [("dense", 9), ("event", 5)])
+def test_weave_step_has_no_gather_or_scatter(one_chip, weave, points):
+    """The weave scan's loop body (`dram.tick`, and `dram.next_event` in
+    the event engine) holds no gather or scatter at the Mess benchmark
+    cells' shapes (9 and 5 paces, 12 windows): the TPU runs those one
+    index at a time, and they held most of a scan step when `tick`
+    read its bank fields with `take_along_axis`.  The whole body is
+    searched, since the compiler drops the `op_name` of the gathers it
+    rewrites."""
+    from repro.core import get_stage
+    from repro.core.mess import _sweep_fn
+    cfg = get_stage("10-delay-buffer", preset=PRESET, weave=weave,
+                    windows=12, warmup=4)
+    s = jax.ShapeDtypeStruct((points,), jnp.int32, sharding=one_chip)
+    text = _sweep_fn(cfg).lower((s, s)).compile().as_text()
+    comps = _computations(text)
+    bodies = re.findall(r' while\(.*body=%([\w.\-]+).*'
+                        r'op_name="[^"]*/weave/while"', text)
+    assert len(bodies) == 1, bodies
+    body = _reachable(comps, bodies)
+    assert len(body) > 1
+    found = [line.strip()[:160] for c in sorted(body) for line in comps[c]
+             if re.search(r"\s(gather|scatter)\(", line)]
+    assert not found, found
 
 
 @pytest.mark.parametrize("weave", ["event", "dense"])

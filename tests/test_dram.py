@@ -7,6 +7,7 @@ interface; our DRAM model must therefore be timing-legal).
 """
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core import dram
 from repro.core.dram import SchedulerPolicy
@@ -184,3 +185,88 @@ def test_next_event_is_a_lower_bound():
                                 f"(ch {c}, t {tau} < ev {int(ev[c])})")
 
     prop()
+
+
+# ---- one-hot match planes against indexed gathers and scatters ------------
+# `tick` and `next_event` read per-bank state per queue entry, and write
+# the selected command's bank and slot, through one-hot match planes
+# (`dram._match`).  These cases hold each form to the indexed
+# `take_along_axis` / `.at[]` form it replaces, on random states of
+# every preset's geometry and of a two-socket queue.
+
+ONE_HOT_CASES = [(p, q) for p in ("ddr4_2666", "ddr5_4800", "hbm2e")
+                 for q in (256, 512)]
+
+
+def _random_queue_banks(preset, Q, seed):
+    """Random (C, Q) slots and (C, RB) bank fields: a third of the slots
+    invalid with ``fbank = 0`` (as `init_queue` leaves them), a quarter of
+    the banks precharged (``open_row = -1``)."""
+    from repro.core.presets import get_preset
+
+    d = get_preset(preset)
+    C, RB = d.n_channels, d.banks_per_channel
+    rng = np.random.default_rng(seed)
+    valid = rng.random((C, Q)) < 2 / 3
+    fbank = np.where(valid, rng.integers(0, RB, (C, Q)), 0).astype(np.int32)
+    open_row = np.where(rng.random((C, RB)) < 0.25, -1,
+                        rng.integers(0, 1 << 16, (C, RB))).astype(np.int32)
+    timer = rng.integers(-(1 << 20), 1 << 28, (C, RB)).astype(np.int32)
+    return d, rng, valid, fbank, open_row, timer
+
+
+@pytest.mark.parametrize("preset,Q", ONE_HOT_CASES)
+def test_one_hot_bank_reads_equal_take_along_axis(preset, Q):
+    d, rng, valid, fbank, open_row, timer = _random_queue_banks(preset, Q, 1)
+    C, RB = d.n_channels, d.banks_per_channel
+    match = dram._match(jnp.asarray(fbank), RB)
+    assert match.shape == (C, RB, Q)
+    assert (np.asarray(match).sum(axis=1) == 1).all()   # one bank a slot
+    pred = rng.random((C, RB)) < 0.5
+    for field in (open_row, timer, pred):
+        np.testing.assert_array_equal(
+            np.asarray(dram._gather(jnp.asarray(field), match)),
+            np.take_along_axis(field, fbank, axis=1))
+    # the hit-pending plane: any entry on the bank, over valid slots only
+    cond = valid & (rng.random((C, Q)) < 0.3)
+    cidx = np.arange(C)
+    want = jnp.zeros((C, RB), bool).at[cidx[:, None], fbank].max(cond)
+    np.testing.assert_array_equal(
+        np.asarray(dram._select(match, jnp.asarray(cond)[:, None, :], 2)),
+        np.asarray(want))
+
+
+@pytest.mark.parametrize("preset,Q", ONE_HOT_CASES)
+def test_one_hot_selected_writes_equal_at_set(preset, Q):
+    d, rng, valid, fbank, open_row, timer = _random_queue_banks(preset, Q, 2)
+    C, RB = d.n_channels, d.banks_per_channel
+    cidx = np.arange(C)
+    sel = rng.integers(0, Q, C).astype(np.int32)
+    sel_match = dram._match(jnp.asarray(sel), Q)
+    row = rng.integers(-1, 1 << 16, (C, Q)).astype(np.int32)
+    for field in (fbank, row, valid):
+        np.testing.assert_array_equal(
+            np.asarray(dram._select(sel_match, jnp.asarray(field), 1)),
+            field[cidx, sel])
+    # selected-bank writes, gated per channel like an ACT/PRE grant
+    s_fb = fbank[cidx, sel]
+    flag = rng.random(C) < 0.5
+    new = rng.integers(0, 1 << 28, C).astype(np.int32)
+    bsel = (cidx, s_fb)
+    for old in (open_row, timer):
+        want = jnp.asarray(old).at[bsel].set(
+            jnp.where(flag, jnp.maximum(old[bsel], new), old[bsel]))
+        got = jnp.where(dram._match(jnp.asarray(s_fb), RB) & flag[:, None],
+                        jnp.maximum(old, new[:, None]), old)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    # the served-slot plane and the telemetry histogram row
+    want = jnp.zeros((C, Q), jnp.int32).at[cidx, sel].set(
+        flag.astype(np.int32))
+    got = (sel_match & flag[:, None]).astype(jnp.int32)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    bucket = rng.integers(0, dram.N_HIST, C).astype(np.int32)
+    want = jnp.zeros((C, dram.N_HIST), jnp.int32).at[cidx, bucket].add(
+        flag.astype(np.int32))
+    got = (dram._match(jnp.asarray(bucket), dram.N_HIST)
+           & flag[:, None]).astype(jnp.int32)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
