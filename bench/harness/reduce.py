@@ -71,22 +71,31 @@ def reduce(device_ops: dict, spans: list, top: int = 10) -> dict:
         device_ops: ``{device: [(start, end, op name), ...]}`` in seconds.
         spans: the harness's host spans ``[(start, end, name), ...]``;
             the window runs from the first call's start to the last
-            call's end.
+            call's end, or to the last op on any device where that is
+            earlier: a profiler that has run out of room for op events
+            goes on recording host spans, and the calls that start after
+            its last op are left out.
     Returns:
         ``busy_s`` (union of op intervals, averaged over the devices),
-        ``window_s``, ``call_gap_s`` (per call: time in the call with no
-        op on any device), and ``device_ops`` / ``idle_gaps`` lists of
-        ``[name, seconds]`` (the ops that took most time summed over the
-        devices; the longest idle stretches of the first device,
-        named by the host span they fall in).
+        ``window_s``, ``call_gap_s`` (per call read: time in the call
+        with no op on any device), ``op_tail_s`` (the last call's end
+        less the last op's end), and ``device_ops`` / ``idle_gaps``
+        lists of ``[name, seconds]`` (the ops that took most time
+        summed over the devices; the longest idle stretches of the
+        first device, named by the host span they fall in).
     """
     calls = sorted((s, e) for s, e, n in spans if n == CALL)
     if not calls or not any(device_ops.values()):
         return {}
-    lo, hi = calls[0][0], calls[-1][1]
     merged = {d: union(evs) for d, evs in device_ops.items()}
-    busy = [covered(m, lo, hi) for m in merged.values()]
     anyop = union([iv for m in merged.values() for iv in m])
+    last_op = anyop[-1][1]
+    lo, hi = calls[0][0], min(calls[-1][1], last_op)
+    tail = calls[-1][1] - last_op
+    calls = [(s, e) for s, e in calls if s < hi]
+    if not calls:
+        return {}
+    busy = [covered(m, lo, hi) for m in merged.values()]
     per_op: dict = {}
     for evs in device_ops.values():
         for s, e, name in evs:
@@ -99,6 +108,7 @@ def reduce(device_ops: dict, spans: list, top: int = 10) -> dict:
     return dict(
         busy_s=sum(busy) / len(busy), window_s=hi - lo,
         call_gap_s=[(e - s) - covered(anyop, s, e) for s, e in calls],
+        op_tail_s=tail,
         device_ops=[[k, v] for k, v in sorted(
             per_op.items(), key=lambda kv: -kv[1])[:top]],
         idle_gaps=[[n, v] for n, v in idle[:top]])

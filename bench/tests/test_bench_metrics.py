@@ -38,7 +38,7 @@ def test_replay_rerun_counts_flagged_rows():
 def test_trace_readers():
     red = dict(busy_s=2.0, window_s=2.5, call_gap_s=[0.01, 0.03])
     ctx = _ctx("mess-ddr4-s10.saturated", trace=red, traced=2)
-    windows = 2 * 9 * 12
+    windows = 2 * 9 * ctx["cell"].windows
     assert metric_reader("device_ms_per_window")(ctx) == pytest.approx(
         2000.0 / windows)
     assert metric_reader("host_gap_ms_per_call")(ctx) == pytest.approx(20.0)

@@ -5,7 +5,7 @@ import collections
 import pytest
 
 from conftest import small_spec
-from harness.spec import load_benchmark, metric_reader
+from harness.spec import load_benchmark, load_cell, metric_reader
 from test_bench_metrics import _ctx
 from test_bench_run import _result
 
@@ -15,22 +15,25 @@ RECORDER = ("rerun_row_share", "launched_steps_per_window", "event_step_fill",
 
 def _one_replay_call(t):
     """What `replay_suite` records in a call of the damov6 cell that
-    starts at ``t``: 5 of 6 rows re-run dense."""
+    starts at ``t``: 5 of 6 rows re-run dense, the event pass using half
+    of its budget."""
     from repro.obs.spans import Count, Span
 
+    config = load_cell("replay-ddr4-s07.damov6")["config"]
+    windows, kept = config["windows"], config["windows"] - config["warmup"]
     return [
         Span("repro.replay.inputs", "repro.replay.suite", t + 0.1, t + 0.2),
         Span("repro.replay.event.fetch", "repro.replay.event", t + 1, t + 3),
         Span("repro.replay.event", "repro.replay.suite", t + 0.5, t + 3),
         Count("repro.rows.event", t + 3, 6),
-        Count("repro.steps.launched", t + 3, 6 * 12 * 199),
-        Count("repro.steps.event_used", t + 3, 4776),
-        Count("repro.steps.event_budget", t + 3, 6 * 8 * 199),
+        Count("repro.steps.launched", t + 3, 6 * windows * 199),
+        Count("repro.steps.event_used", t + 3, 3 * kept * 199),
+        Count("repro.steps.event_budget", t + 3, 6 * kept * 199),
         Span("repro.replay.dense.fetch", "repro.replay.dense", t + 4, t + 7),
         Span("repro.replay.dense", "repro.replay.suite", t + 3.5, t + 7),
         Count("repro.rows.dense", t + 7, 0),
         Count("repro.rows.rerun", t + 7, 5),
-        Count("repro.steps.launched", t + 7, 5 * 12 * 635),
+        Count("repro.steps.launched", t + 7, 5 * windows * 635),
         Span("repro.replay.runtime", "repro.replay.suite", t + 7, t + 7.5),
         Span("repro.replay.suite", None, t, t + 8),
     ]
@@ -100,12 +103,13 @@ def test_recorder_readers_read_nothing_without_the_recorder(monkeypatch,
 def test_event_step_fill_needs_an_event_launch(monkeypatch):
     from repro.obs import spans
 
+    windows = load_cell("mess-ddr4-s10.saturated")["config"]["windows"]
     ring = collections.deque([
         spans.Span("repro.mess.dense", "repro.mess.mix", 1.0, 2.0),
         spans.Span("repro.mess.mix", "repro.mess.sweep", 0.5, 2.5),
         spans.Span("repro.mess.sweep", None, 0.5, 2.5),
         spans.Count("repro.rows.dense", 2.0, 9),
-        spans.Count("repro.steps.launched", 2.0, 9 * 12 * 635)])
+        spans.Count("repro.steps.launched", 2.0, 9 * windows * 635)])
     monkeypatch.setattr(spans, "_ring", ring)
     ctx = _ctx("mess-ddr4-s10.saturated")
     ctx["calls"] = [(0.0, 3.0, {})]
