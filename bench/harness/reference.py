@@ -1,19 +1,43 @@
 """The plain reference: the platform simulated one DRAM tick per step.
 
 A stand-alone statement of the semantics the benchmark holds the
-program to.  It imports nothing of the program and takes every size
-and timing from the configuration file (``platform`` group), so a
-later change to the program cannot move it.  It is deliberately the
+program to.  It imports nothing of the program and takes every fact
+about the memory device and the frontend from the configuration file
+(``platform`` group: sizes, timings, address map, refresh mode,
+sockets), so a later change to the program cannot move it and a new
+device is added by a configuration file alone.  It is deliberately the
 simplest form of the simulation:
 
 * one scan step per DRAM tick of every window (no event horizon, no
   knee router, no re-run of saturated points, no sharding);
 * one device, ``jax.vmap`` over the points or applications of a call;
 * only what the configurations use: the picosecond clock crossing,
-  the Skylake XOR address map on the DDR4 geometry, FR-FCFS with an
-  open page and watermark write drain, all-bank refresh, the stride
+  FR-FCFS with an open page and watermark write drain, the stride
   prefetcher, the PI-controlled immediate response, the MSHR closed
-  loop, the Mess pace generator and the solo-trace replay frontend.
+  loop, the Mess pace generator and the solo-trace replay frontend;
+* ``address_map``: ``skylake_xor``, the DRAMDig fold of the 6-channel,
+  2-rank, 16-bank DDR4 geometry (and no other), or ``xor_fold``, the
+  generic fold for any geometry: with ``C`` channels, ``R`` ranks,
+  ``B`` banks a rank, ``L`` lines a row and a ``2**k``-row bank, a line
+  ``l`` goes to channel ``(l ^ l>>6 ^ l>>12 ^ l>>18) % C``, bank
+  ``((l // C // L) ^ l>>13) % B``, rank ``(l>>8 ^ l>>17) % R`` and row
+  ``(l>>9) & (2**k - 1)``;
+* ``refresh``: ``all_bank`` closes the whole rank at its deadline and
+  blocks it for ``tRFC``; ``same_bank`` (REFsb) closes and blocks only
+  the rank's target bank, which starts at bank 0 and moves on by one,
+  modulo ``banks_per_rank``, at each of the rank's deadlines
+  (``tREFI`` is then the per-rank REFsb cadence);
+* ``n_sockets``: ``n_cores`` counts the cores of every socket, all of
+  them traffic cores but the last, the one chase probe they share.
+  The controller queue holds ``queue_depth`` slots a socket.  With
+  ``socket_channels`` ``partitioned`` a request's channel is folded
+  into its socket's share of ``n_channels / n_sockets`` channels;
+  ``interleaved`` leaves it as the address map gives it.
+
+Departures from the program's own description: none in what is
+simulated.  Requests enter the queues chase first, then in issue
+order, then by core id, sorted on one integer key that holds the core
+id in 6 bits, so ``n_cores`` is at most 64 (two sockets of 24 fit).
 
 ``fdt`` is the float type of every floating-point quantity the
 simulation carries (latency sums, the PI estimate, the closed-loop
@@ -34,6 +58,12 @@ import numpy as np
 _BIG = 1 << 28
 VIEWS = ("sim_bw_gbs", "sim_lat_ns", "if_bw_gbs", "if_lat_ns",
          "app_bw_gbs", "app_lat_ns", "chase_lat_ns")
+ADDRESS_MAPS = ("skylake_xor", "xor_fold")
+REFRESH_MODES = ("all_bank", "same_bank")
+SOCKET_CHANNELS = ("interleaved", "partitioned")
+#: channels, ranks, banks a rank, lines a row and rows of the geometry
+#: the Skylake fold was reverse-engineered on (DDR4-2666, 6 channels)
+_DDR4 = (6, 2, 16, 128, 1 << 17)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -60,11 +90,17 @@ class Platform:
     chase_region_bits: int
     pi_keep: float
     pi_blend: float
+    n_sockets: int
+    socket_channels: str
     # DRAM device and controller
     n_channels: int
     ranks: int
     banks_per_rank: int
     bank_groups: int
+    rows_per_bank: int
+    lines_per_row: int
+    address_map: str
+    refresh: str
     line_bytes: int
     dram_ps_per_clk: int
     queue_depth: int
@@ -100,6 +136,31 @@ class Platform:
                              f"platform keys {sorted(missing)}")
         return cls(**{k: p[k] for k in names})
 
+    def __post_init__(self):
+        def refuse(key, why):
+            raise ValueError(f"platform key {key!r} = {getattr(self, key)!r}:"
+                             f" {why}")
+
+        for key, allowed in (("address_map", ADDRESS_MAPS),
+                             ("refresh", REFRESH_MODES),
+                             ("socket_channels", SOCKET_CHANNELS)):
+            if getattr(self, key) not in allowed:
+                refuse(key, f"not one of {list(allowed)}")
+        if self.address_map == "skylake_xor" and self.geometry != _DDR4:
+            refuse("address_map", "the Skylake fold is defined on the "
+                   f"DDR4 geometry {_DDR4} (channels, ranks, banks, lines "
+                   f"a row, rows) only, not on {self.geometry}")
+        if self.rows_per_bank & (self.rows_per_bank - 1):
+            refuse("rows_per_bank", "not a power of two")
+        if not 1 <= self.n_sockets or self.n_cores % self.n_sockets:
+            refuse("n_sockets", f"does not divide n_cores {self.n_cores}")
+        if self.n_cores > 64:
+            refuse("n_cores", "over 64, the admission key's core field")
+        if (self.socket_channels == "partitioned"
+                and self.n_channels % self.n_sockets):
+            refuse("n_channels", f"not divisible by n_sockets "
+                   f"{self.n_sockets} for partitioned channels")
+
     @property
     def n_traffic(self) -> int:
         return self.n_cores - 1
@@ -113,6 +174,11 @@ class Platform:
         return self.ranks * self.banks_per_rank
 
     @property
+    def geometry(self) -> tuple:
+        return (self.n_channels, self.ranks, self.banks_per_rank,
+                self.lines_per_row, self.rows_per_bank)
+
+    @property
     def ticks_per_window(self) -> int:
         """Picosecond clock crossing: DRAM ticks while dramPs < cpuPs."""
         return math.ceil(self.window_cycles * self.cpu_ps_per_clk
@@ -123,15 +189,18 @@ class Platform:
                 // self.dram_ps_per_clk)
 
 
-# ---- address map: DRAMDig-style XOR fold on the DDR4 geometry ---------
+# ---- address maps -----------------------------------------------------
 
 def _bit(x, i):
     return (x >> i) & 1
 
 
-def decode(line):
-    """(channel, rank, bank, row) of uint32 cache-line indices."""
+def decode(p: Platform, line):
+    """(channel, rank, bank, row) of uint32 cache-line indices under the
+    configuration's address map."""
     line = line.astype(jnp.uint32)
+    if p.address_map == "xor_fold":
+        return _xor_fold(p, line)
     mc = _bit(line, 0) ^ _bit(line, 6) ^ _bit(line, 11) ^ _bit(line, 17)
     ch3 = ((line >> 1) ^ (line >> 7) ^ (line >> 13) ^ (line >> 19)) % 3
     bank = (_bit(line, 2) ^ _bit(line, 12)) \
@@ -141,6 +210,20 @@ def decode(line):
     rank = _bit(line, 8) ^ _bit(line, 18)
     row = (line >> 9) & 0x1FFFF
     return ((mc * 3 + ch3).astype(jnp.int32), rank.astype(jnp.int32),
+            bank.astype(jnp.int32), row.astype(jnp.int32))
+
+
+def _xor_fold(p: Platform, line):
+    """The generic fold: low and row bits hashed into channel, bank and
+    rank, so a sequential stream scatters over all of them."""
+    channel = (line ^ (line >> 6) ^ (line >> 12) ^ (line >> 18)) \
+        % p.n_channels
+    in_channel = line // p.n_channels
+    bank = ((in_channel // p.lines_per_row) ^ (line >> 13)) \
+        % p.banks_per_rank
+    rank = ((line >> 8) ^ (line >> 17)) % p.ranks
+    row = (line >> 9) & (p.rows_per_bank - 1)
+    return (channel.astype(jnp.int32), rank.astype(jnp.int32),
             bank.astype(jnp.int32), row.astype(jnp.int32))
 
 
@@ -164,6 +247,7 @@ class Banks(NamedTuple):
     next_pre: jnp.ndarray
     faw: jnp.ndarray
     next_ref: jnp.ndarray
+    ref_bank: jnp.ndarray
     bus_free: jnp.ndarray
     wtr_until: jnp.ndarray
     rtw_until: jnp.ndarray
@@ -181,7 +265,7 @@ class Stats(NamedTuple):
 
 
 def _init_queue(p: Platform) -> Queue:
-    z = jnp.zeros((p.n_channels, p.queue_depth), jnp.int32)
+    z = jnp.zeros((p.n_channels, p.queue_depth * p.n_sockets), jnp.int32)
     return Queue(z, z, z, z, z, z - 1, z)
 
 
@@ -195,6 +279,7 @@ def _init_banks(p: Platform) -> Banks:
         # refresh deadlines staggered across the ranks of a channel
         next_ref=(p.tREFI + jnp.arange(R, dtype=jnp.int32)[None, :]
                   * (p.tREFI // R) + jnp.zeros((C, R), jnp.int32)),
+        ref_bank=jnp.zeros((C, R), jnp.int32),
         bus_free=zc, wtr_until=zc, rtw_until=zc, last_rank=zc,
         drain=jnp.zeros((C,), bool))
 
@@ -218,9 +303,15 @@ def _tick(p: Platform, fdt, q: Queue, b: Banks, t, active):
     def gather(field):
         return jnp.take_along_axis(field, q.fbank, axis=1)
 
-    # all-bank refresh: close the rank and block it for tRFC
+    # refresh: close the rank (all-bank) or its target bank (same-bank)
+    # and block it for tRFC
     ref_due = active[:, None] & (t_r >= b.next_ref)
     refmask = jnp.repeat(ref_due, nb, axis=1)
+    ref_bank = b.ref_bank
+    if p.refresh == "same_bank":
+        refmask = refmask & (bank_ids % nb == jnp.repeat(ref_bank, nb,
+                                                           axis=1))
+        ref_bank = jnp.where(ref_due, (ref_bank + 1) % nb, ref_bank)
     open_row = jnp.where(refmask, -1, b.open_row)
     next_act = jnp.where(refmask, jnp.maximum(b.next_act, t_r + p.tRFC),
                          b.next_act)
@@ -323,8 +414,8 @@ def _tick(p: Platform, fdt, q: Queue, b: Banks, t, active):
     nact = nact.at[bsel].set(jnp.where(
         s_pre, jnp.maximum(nact[bsel], t + p.tRP), nact[bsel]))
 
-    b = Banks(open_row, nact, nrd, nwr, npre, faw, next_ref, bus_free,
-              wtr_until, rtw_until, last_rank, drain)
+    b = Banks(open_row, nact, nrd, nwr, npre, faw, next_ref, ref_bank,
+              bus_free, wtr_until, rtw_until, last_rank, drain)
     served = jnp.zeros_like(q.valid).at[cidx, sel].set(
         s_cas.astype(jnp.int32))
     q = q._replace(valid=q.valid & (1 - served))
@@ -541,7 +632,11 @@ def _inject(p: Platform, q: Queue, cand: Cand, w):
     n = p.n_cores * p.cand
     flat = jax.tree_util.tree_map(lambda a: a.reshape(n), cand)
     core_of = jnp.repeat(jnp.arange(p.n_cores, dtype=jnp.int32), p.cand)
-    channel, rank, bank, row = decode(flat.line)
+    channel, rank, bank, row = decode(p, flat.line)
+    if p.socket_channels == "partitioned":
+        share = C // p.n_sockets
+        socket = core_of // (p.n_cores // p.n_sockets)
+        channel = socket * share + channel % share
     ch = jnp.where(flat.valid, channel, C)
     key = ((1 - flat.is_chase.astype(jnp.int32)) * (1 << 24)
            + flat.issue_cycle * 64 + core_of)

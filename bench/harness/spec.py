@@ -1,8 +1,9 @@
 """Finding a cell's files by the names in ``BENCHMARK.json``.
 
 A cell ``<config>.<traffic>`` reads ``bench/configs/<config>.json`` (the
-deployment: the program's stage and preset, the windows it simulates,
-every size the reference needs, and the limits of the comparison) and
+deployment: the program's stage, preset and overrides, the windows it
+simulates, every fact about the memory device and the frontend that the
+reference needs, and the limits of the comparison) and
 ``bench/traffic/<traffic>.json`` (the mix of points or applications
 one call simulates).  A per-layer metric ``<name>`` is read by
 ``bench/metrics/<name>.py``.  Nothing here knows any particular cell,
@@ -58,6 +59,12 @@ def load_cell(name: str, bench: dict | None = None) -> dict:
     if config.get("name") != work["config"]:
         raise SpecError(f"{conf_entry['file']} names {config.get('name')!r},"
                         f" not {work['config']!r}")
+    from harness.reference import Platform
+
+    try:
+        Platform.from_config(config)
+    except ValueError as e:
+        raise SpecError(f"{conf_entry['file']}: {e}") from None
     traffic = _json("traffic", work["traffic"])
 
     def applies(metric):

@@ -54,7 +54,12 @@ def main(argv=None) -> int:
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     from harness.runner import run
 
-    return run(spec, args.seed, args.seconds, bool(args.trace), T_START)
+    try:
+        return run(spec, args.seed, args.seconds, bool(args.trace), T_START)
+    except SpecError as e:
+        # the configuration and the program's stage disagree
+        print(f"bench: {e}", file=sys.stderr)
+        return 2
 
 
 if __name__ == "__main__":

@@ -13,7 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import BENCH, ROOT, small_spec
+from conftest import BENCH, ROOT, device_spec, small_spec
 from harness import compare
 from harness.cells import Cell
 from harness.runner import run
@@ -66,6 +66,8 @@ def fresh_programs():
 
 
 CELLS = ["mess-ddr4-s10.saturated", "replay-ddr4-s07.damov6"]
+#: test-only configurations of other devices (`conftest.DEVICE_CONFIGS`)
+DEVICES = ["mess-ddr5-s10", "mess-hbm2e-s10-2s"]
 
 
 @pytest.mark.parametrize("name", CELLS)
@@ -86,10 +88,10 @@ def test_traced_run_reports_per_layer_metrics():
                                       "rerun_share", "compiles_in_window"}
 
 
-@pytest.mark.parametrize("name", CELLS)
+@pytest.mark.parametrize("name", CELLS + DEVICES)
 def test_control_is_not_correct(name):
     """The reference in bfloat16 in the program's place fails a limit."""
-    spec = small_spec(name)
+    spec = device_spec(name) if name in DEVICES else small_spec(name)
     cell = Cell(spec, SEED)
     verdict = compare.judge(cell.kind, [cell.reference(jnp.bfloat16)],
                             cell.reference(), spec["config"]["limits"])
@@ -189,6 +191,43 @@ def test_fault_is_not_correct(name, fault, monkeypatch, fresh_programs):
     plant = FAULTS[fault][spec["traffic"]["kind"] == "replay"]
     plant(monkeypatch)
     result = _result(spec)
+    assert result["correct"] is False
+    assert result["failed"] > 0
+    assert any(c["value"] > c["limit"] for c in result["checks"].values())
+
+
+# ---- a program that runs another machine than the configuration states ---
+
+def _program_changed(monkeypatch, change):
+    """`Cell.prepare` as it is, then the program's stage rewritten by
+    ``change``: the platform group, and so the reference, stay as
+    stated."""
+    prepare = Cell.prepare
+
+    def changed(self):
+        prepare(self)
+        self.cfg = change(self.cfg)
+
+    monkeypatch.setattr(Cell, "prepare", changed)
+
+
+def _all_bank_refresh(cfg):
+    dram = dataclasses.replace(cfg.platform.dram, same_bank_refresh=False)
+    return dataclasses.replace(
+        cfg, platform=dataclasses.replace(cfg.platform, dram=dram))
+
+
+def _one_socket(cfg):
+    return dataclasses.replace(cfg, n_sockets=1)
+
+
+@pytest.mark.parametrize("device, change", [
+    ("mess-ddr5-s10", _all_bank_refresh),
+    ("mess-hbm2e-s10-2s", _one_socket),
+], ids=["ddr5_all_bank_refresh", "hbm2e_one_socket"])
+def test_device_fault_is_not_correct(device, change, monkeypatch):
+    _program_changed(monkeypatch, change)
+    result = _result(device_spec(device))
     assert result["correct"] is False
     assert result["failed"] > 0
     assert any(c["value"] > c["limit"] for c in result["checks"].values())
